@@ -68,8 +68,8 @@ SECTION_KEYS = {
     "incremental": ("graph", "ws_mb", "spec_key", "spec", "n_rounds",
                     "retries", "kernel_fallbacks"),
     "service": ("ms", "kernel_fallbacks"),
-    # sharded runs its mesh sweep in a subprocess, so no spec echo and no
-    # kernel-fallback attribution land in the parent's rows
+    # sharded rows carry no spec echo: each row aggregates a whole update
+    # stream over one mesh
     "sharded": ("graph", "colors", "kernel_fallbacks"),
     "lm_step": ("params_mb", "kernel_fallbacks"),
 }
@@ -183,6 +183,8 @@ def main(argv=None) -> None:
         raise SystemExit(f"unknown section(s) {unknown}; known: {SECTIONS}")
     if scale not in SCALES:
         raise SystemExit(f"unknown scale {scale!r}; known: {SCALES}")
+    from repro import compile_cache
+    compile_cache.enable()
     for name in names:
         print(f"\n===== bench: {name} (scale={scale}) =====", flush=True)
         t0 = time.perf_counter()

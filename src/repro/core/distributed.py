@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro import registry
 from repro.graphs.csr import CSRGraph, FILL, to_ell
@@ -161,7 +161,7 @@ def build_rsoc_distributed(mesh: Mesh, axis: str, ctx: PassContext,
 
     f = shard_map(body, mesh=mesh,
                   in_specs=(P(*((axes if len(axes) > 1 else (axes[0],)) + (None,))), P()),
-                  out_specs=(P(), P(), P(), P()), check_rep=False)
+                  out_specs=(P(), P(), P(), P()), check_vma=False)
     return jax.jit(f)
 
 
@@ -221,7 +221,7 @@ def build_cat_distributed(mesh: Mesh, axis: str, ctx: PassContext,
 
     f = shard_map(body, mesh=mesh,
                   in_specs=(P(*((axes if len(axes) > 1 else (axes[0],)) + (None,))), P()),
-                  out_specs=(P(), P(), P(), P()), check_rep=False)
+                  out_specs=(P(), P(), P(), P()), check_vma=False)
     return jax.jit(f)
 
 
@@ -298,7 +298,7 @@ def build_rsoc_halo(mesh: Mesh, axis: str, plan_shapes: dict,
     vec = P(axes if len(axes) > 1 else axes[0])
     f = shard_map(body, mesh=mesh,
                   in_specs=(row, vec, vec, vec, vec, vec),
-                  out_specs=(vec, P(), P(), P()), check_rep=False)
+                  out_specs=(vec, P(), P(), P()), check_vma=False)
     return jax.jit(f)
 
 
@@ -395,7 +395,7 @@ def build_sharded_scratch(mesh: Mesh, axis: str, D: int, n_loc: int,
     vec = P(axes if len(axes) > 1 else axes[0])
     f = shard_map(body, mesh=mesh,
                   in_specs=(row, vec, vec, vec, vec, vec, vec),
-                  out_specs=(vec, P(), P(), P(), P()), check_rep=False)
+                  out_specs=(vec, P(), P(), P(), P()), check_vma=False)
     return jax.jit(f)
 
 
@@ -477,7 +477,7 @@ def build_sharded_repair(mesh: Mesh, axis: str, D: int, n_loc: int,
     vec = P(axes if len(axes) > 1 else axes[0])
     f = shard_map(body, mesh=mesh,
                   in_specs=(row, vec, vec, vec, vec, vec, vec, vec, vec),
-                  out_specs=(vec, P(), P(), P(), P()), check_rep=False)
+                  out_specs=(vec, P(), P(), P(), P()), check_vma=False)
     return jax.jit(f)
 
 

@@ -37,8 +37,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs, registry
 from repro.core import coloring as col
@@ -201,6 +203,15 @@ def _mesh_size(mesh, axis: str) -> int:
     return int(np.prod([mesh.shape[a] for a in axis.split(",")]))
 
 
+def _on_mesh(mesh, axis: str, *arrays):
+    """Place leading-shard-axis arrays so shard d's rows live on device d of
+    ``mesh`` (not all on the default device).  The placement mesh has the
+    same devices with automatic axes, so the host-planned wave arrays that
+    meet these in the wave kernels need no sharding of their own."""
+    sh = NamedSharding(Mesh(mesh.devices, mesh.axis_names), P(axis))
+    return tuple(jax.device_put(np.asarray(a), sh) for a in arrays)
+
+
 def _aligned_n_loc(n: int, D: int, n_chunks: int) -> int:
     """Per-shard row-table height: the block size rounded up so every
     shard's sweep divides into n_chunks (at D=1 this IS ``prepare``'s
@@ -314,12 +325,12 @@ def sharded_state(g: CSRGraph, mesh, axis: str = "data", seed: int = 0,
 
     n_tab = n_loc + plan.max_g_cap
     hb = (1 + int(r)) * D * (plan.max_b_cap + 3) * 4
+    ell, osrc, odst, pri_dev, colors_dev = _on_mesh(
+        mesh, axis, plan.ell_local, plan.ovf_src, plan.ovf_dst, pri_tab,
+        np.asarray(tab).reshape(D, n_tab))
     return ShardedColoringState(
-        ell=jnp.asarray(plan.ell_local),
-        ovf_src=jnp.asarray(plan.ovf_src),
-        ovf_dst=jnp.asarray(plan.ovf_dst),
-        pri_tab=jnp.asarray(pri_tab),
-        colors_tab=jnp.asarray(tab).reshape(D, n_tab),
+        ell=ell, ovf_src=osrc, ovf_dst=odst, pri_tab=pri_dev,
+        colors_tab=colors_dev,
         boundary=plan.boundary, n_boundary=plan.n_boundary,
         ghost_ids=plan.ghost_ids, ghost_flat=plan.ghost_flat,
         n_ghost=plan.n_ghost,
@@ -512,12 +523,12 @@ def _replan(state: ShardedColoringState, need_b: int,
             flat = np.asarray(state.colors_tab[:, :n_loc]).reshape(-1)
             colors_tab[d, n_loc:n_loc + ng] = flat[state.row_of[ids]]
     obs_metrics.counter("sharded.replan").inc()
+    ell, osrc, odst, pri_dev, colors_dev = _on_mesh(
+        state.mesh, state.axis, plan.ell_local, plan.ovf_src, plan.ovf_dst,
+        pri_tab, colors_tab)
     return dataclasses.replace(
-        state, ell=jnp.asarray(plan.ell_local),
-        ovf_src=jnp.asarray(plan.ovf_src),
-        ovf_dst=jnp.asarray(plan.ovf_dst),
-        pri_tab=jnp.asarray(pri_tab),
-        colors_tab=jnp.asarray(colors_tab),
+        state, ell=ell, ovf_src=osrc, ovf_dst=odst, pri_tab=pri_dev,
+        colors_tab=colors_dev,
         boundary=plan.boundary, n_boundary=plan.n_boundary,
         ghost_ids=plan.ghost_ids, ghost_flat=plan.ghost_flat,
         n_ghost=plan.n_ghost, replans=state.replans + 1)
@@ -753,12 +764,12 @@ def encode_oracle_sharded(g: CSRGraph, mesh, axis: str = "data", *,
                 colors_rel[plan.ghost_ids[d, :ng]]
     n_used = int(colors.max()) + 1 if len(colors) else 1
     C = max(32, -(-n_used // 32) * 32)   # headroom for future repairs
+    ell, osrc, odst, pri_dev, colors_dev = _on_mesh(
+        mesh, axis, plan.ell_local, plan.ovf_src, plan.ovf_dst, pri_tab,
+        colors_tab)
     return ShardedColoringState(
-        ell=jnp.asarray(plan.ell_local),
-        ovf_src=jnp.asarray(plan.ovf_src),
-        ovf_dst=jnp.asarray(plan.ovf_dst),
-        pri_tab=jnp.asarray(pri_tab),
-        colors_tab=jnp.asarray(colors_tab),
+        ell=ell, ovf_src=osrc, ovf_dst=odst, pri_tab=pri_dev,
+        colors_tab=colors_dev,
         boundary=plan.boundary, n_boundary=plan.n_boundary,
         ghost_ids=plan.ghost_ids, ghost_flat=plan.ghost_flat,
         n_ghost=plan.n_ghost,

@@ -52,16 +52,18 @@ def from_edges(n_vertices: int, edges: np.ndarray, symmetrize: bool = True) -> C
     """Build a CSR graph from an (m, 2) edge array; dedups and removes self-loops."""
     edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
     edges = edges[edges[:, 0] != edges[:, 1]]
-    if symmetrize:
-        edges = np.concatenate([edges, edges[:, ::-1]], axis=0)
-    # dedup via flat key
+    # dedup via a sorted flat key, decoded back to (src, dst): sorting the
+    # keys themselves is several times faster than argsort + gather at the
+    # 10^7-10^8 edges of paper-scale R-MAT graphs, with identical output
     key = edges[:, 0] * n_vertices + edges[:, 1]
-    order = np.argsort(key, kind="stable")
-    key = key[order]
+    if symmetrize:
+        key = np.concatenate([key, edges[:, 1] * n_vertices + edges[:, 0]])
+    key.sort()
     keep = np.ones(len(key), dtype=bool)
     keep[1:] = key[1:] != key[:-1]
-    edges = edges[order][keep]
-    src, dst = edges[:, 0], edges[:, 1]
+    key = key[keep]
+    src = key // max(n_vertices, 1)
+    dst = key - src * n_vertices
     counts = np.bincount(src, minlength=n_vertices)
     indptr = np.zeros(n_vertices + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])

@@ -35,13 +35,23 @@ def rmat(scale: int, edge_factor: int = 8, a: float = 0.25, b: float = 0.25,
         raise ValueError("probabilities must sum <= 1")
     probs = np.array([a, b, c, max(d, 0.0)])
     probs = probs / probs.sum()
+    # the quadrant cdf exactly as rng.choice(4, size=m, p=probs) builds it:
+    # choice draws rng.random(m) and returns #(cdf <= u), so thresholding
+    # the same draws gives the same quadrants, and so the same graph, without
+    # the sort-search and int64 temporaries
+    cdf = probs.cumsum()
+    cdf /= cdf[-1]
     src = np.zeros(m, dtype=np.int64)
     dst = np.zeros(m, dtype=np.int64)
-    # vectorized bit-by-bit quadrant sampling
+    # vectorized bit-by-bit quadrant sampling: quadrant q = 2*hi + lo
     for _ in range(scale):
-        q = rng.choice(4, size=m, p=probs)
-        src = (src << 1) | (q >> 1)
-        dst = (dst << 1) | (q & 1)
+        u = rng.random(m)
+        hi = u >= cdf[1]                                  # q in {2, 3}
+        lo = (u >= cdf[0]) ^ hi ^ (u >= cdf[2])           # q in {1, 3}
+        src <<= 1
+        src |= hi
+        dst <<= 1
+        dst |= lo
     g = from_edges(n, np.stack([src, dst], 1))
     if shuffle:
         g = shuffle_vertices(g, seed=seed + 1)

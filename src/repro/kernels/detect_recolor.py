@@ -6,6 +6,7 @@ into Alg. 3's single phase: neighbor colors are gathered ONCE and feed both
 the defect test (same color as a higher-priority neighbor) and the first-fit
 re-color.  The forbidden accumulator is the packed (BV, C//32) bitset of
 DESIGN.md §10 (inline pack + branch-free mex via ``core/bitset.py``).
+Not lowerable for TPU yet: see ``ops.MOSAIC_UNSUPPORTED``.
 """
 from __future__ import annotations
 
@@ -52,7 +53,7 @@ def _detect_recolor_kernel(ell_ref, colors_ref, pri_ref, U_ref, rowc_ref,
 @functools.partial(jax.jit,
                    static_argnames=("C", "row_start", "block_rows", "interpret"))
 def detect_recolor(ell, colors, pri, U_rows, row_start: int, C: int = 64,
-                   block_rows: int = 256, interpret: bool = True):
+                   block_rows: int = 256, interpret: bool = False):
     """Fused RSOC pass for rows [row_start, row_start + R).
 
     ell:    (R, W) neighbor tile for those rows

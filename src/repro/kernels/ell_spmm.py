@@ -7,6 +7,7 @@ in VMEM and reduces on the VPU.  Feature panels bound VMEM use to n*BF*4
 bytes; the ops.py wrapper picks BF accordingly and falls back to the
 segment-sum jnp path for graphs whose node count makes any panel too large
 (page-indirected DMA design for that regime is documented in DESIGN.md).
+Not lowerable for TPU yet: see ``ops.MOSAIC_UNSUPPORTED``.
 """
 from __future__ import annotations
 
@@ -50,7 +51,7 @@ def _ell_spmm_kernel(ell_ref, feats_ref, out_ref, *, op: str, n: int):
                    static_argnames=("op", "block_rows", "block_feats",
                                     "interpret"))
 def ell_spmm(ell, feats, op: str = "sum", block_rows: int = 128,
-             block_feats: int = 128, interpret: bool = True):
+             block_feats: int = 128, interpret: bool = False):
     """Aggregate neighbor features over an ELL table.
 
     ell: (R, W) int32; feats: (n, d) float32/bf16 -> (R, d)

@@ -15,6 +15,10 @@ honest account and the ops.py wrapper falls back to the jnp path when it
 busts the budget.
 
 Grid: one program per BV-row block of the chunk being colored.
+
+The TPU compiler does not lower this kernel yet (the per-row gather from the
+(n,) vector); ``ops.MOSAIC_UNSUPPORTED`` records why, and on TPU the
+dispatcher runs the jnp reference.
 """
 from __future__ import annotations
 
@@ -46,7 +50,7 @@ def _firstfit_kernel(ell_ref, colors_ref, out_ref, ovf_ref, *, C: int, n: int):
 
 @functools.partial(jax.jit, static_argnames=("C", "block_rows", "interpret"))
 def firstfit(ell, colors, C: int = 64, block_rows: int = 256,
-             interpret: bool = True):
+             interpret: bool = False):
     """First-fit colors for every ELL row. Returns (mex (R,), overflow (R,))."""
     R, W = ell.shape
     n = colors.shape[0]

@@ -56,7 +56,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, Lq, Lk,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q: (B, Hq, Lq, D); k, v: (B, Hkv, Lk, D); GQA when Hq > Hkv."""
     B, Hq, Lq, D = q.shape
     _, Hkv, Lk, _ = k.shape

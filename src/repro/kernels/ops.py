@@ -1,10 +1,13 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-``backend='auto'`` uses the Pallas kernel on TPU and the jnp oracle path on
-CPU (this container) — the dry-run therefore lowers the pure-jnp
-memory-efficient paths, while kernels are validated in interpret mode by the
-test suite.  ``backend='pallas_interpret'`` forces the kernel body through the
-Pallas interpreter (CPU-executable, bit-faithful to kernel semantics).
+``backend='auto'`` uses the Pallas kernel on TPU when the TPU compiler
+(Mosaic) accepts it, and the jnp reference path otherwise — on every other
+platform, and on TPU for the kernels listed in ``MOSAIC_UNSUPPORTED``.  An
+explicit ``backend='pallas'`` for a listed kernel raises
+``NotImplementedError`` with the compiler's reason instead of silently
+running the reference.  ``backend='pallas_interpret'`` forces the kernel body
+through the Pallas interpreter (CPU-executable, bit-faithful to kernel
+semantics); that is how the test suite validates every kernel.
 
 The coloring dispatchers take ``impl`` ("bitset" | "dense"), forwarded to
 the jnp refs; the Pallas kernels are the packed-bitset expression by
@@ -47,12 +50,45 @@ from repro.resilience import faults
 VMEM_BUDGET_BYTES = 8 * 2**20
 
 
-def default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+# Kernels the TPU compiler refuses, with its reason (compiled for a described
+# v5e chip; tests/test_tpu_compile.py pins both directions of this table).
+# The four coloring/aggregation kernels gather per row from a VMEM-resident
+# (n,) vector, and Mosaic lowers a gather only within one vreg; the column
+# read ``ell[:, j]`` inside a fori_loop is a dynamic_slice it does not lower
+# either.  Making them lower is a redesign (gather outside the kernel), not a
+# lowering fix, so on TPU ``auto`` runs the jnp reference for them.
+_GATHER_REASON = ("Mosaic: 'Unimplemented primitive in Pallas TPU lowering: "
+                  "dynamic_slice' (column read ell[:, j] in a fori_loop), "
+                  "then 'Only 2D gather is supported' (per-row gather from "
+                  "the VMEM-resident (n,) vector)")
+MOSAIC_UNSUPPORTED = {
+    "firstfit": _GATHER_REASON,
+    "detect_recolor": _GATHER_REASON,
+    "twohop": _GATHER_REASON,
+    "ell_aggregate": _GATHER_REASON,
+}
 
 
-def _resolve(backend: str) -> str:
-    return default_backend() if backend == "auto" else backend
+def default_backend(kernel: str) -> str:
+    """What ``backend='auto'`` runs for ``kernel`` on this process's
+    platform."""
+    if jax.default_backend() == "tpu" and kernel not in MOSAIC_UNSUPPORTED:
+        return "pallas"
+    return "jnp"
+
+
+def _resolve(kernel: str, backend: str) -> str:
+    return default_backend(kernel) if backend == "auto" else backend
+
+
+def _require_mosaic(kernel: str, b: str) -> None:
+    """An explicit ``pallas`` request for a kernel Mosaic refuses raises —
+    it never turns into the reference path without saying so."""
+    if b == "pallas" and kernel in MOSAIC_UNSUPPORTED:
+        raise NotImplementedError(
+            f"{kernel}: the Pallas kernel does not lower for TPU — "
+            f"{MOSAIC_UNSUPPORTED[kernel]}; use backend='jnp' or 'auto' "
+            f"(the jnp reference) or 'pallas_interpret'")
 
 
 def _forced_fallback(kernel: str, b: str) -> str:
@@ -192,7 +228,7 @@ def _mb(b: int) -> str:
 
 def firstfit(ell, colors, C: int = 64, backend: str = "auto",
              impl: str = "bitset", **kw):
-    b = _resolve(backend)
+    b = _resolve("firstfit", backend)
     R, W = ell.shape
     n = colors.shape[0]
     if b != "jnp":
@@ -206,6 +242,7 @@ def firstfit(ell, colors, C: int = 64, backend: str = "auto",
                 f"(the (n,) color vector is not pageable)")
             b = "jnp"
     b = _forced_fallback("firstfit", b)
+    _require_mosaic("firstfit", b)
     _dispatched("firstfit", b)
     if b == "jnp":
         return ref.firstfit_ref(ell, colors, C, impl=impl)
@@ -216,7 +253,7 @@ def firstfit(ell, colors, C: int = 64, backend: str = "auto",
 
 def detect_recolor(ell, colors, pri, U_rows, row_start: int, C: int = 64,
                    backend: str = "auto", impl: str = "bitset", **kw):
-    b = _resolve(backend)
+    b = _resolve("detect_recolor", backend)
     R, W = ell.shape
     n = colors.shape[0]
     if b != "jnp":
@@ -230,6 +267,7 @@ def detect_recolor(ell, colors, pri, U_rows, row_start: int, C: int = 64,
                 f"(the (n,) color/priority vectors are not pageable)")
             b = "jnp"
     b = _forced_fallback("detect_recolor", b)
+    _require_mosaic("detect_recolor", b)
     _dispatched("detect_recolor", b)
     if b == "jnp":
         return ref.detect_recolor_ref(ell, colors, pri, row_start, U_rows, C,
@@ -248,7 +286,7 @@ def twohop(ell_rows, ell_all, colors, pri, U_rows, row_start: int,
     longer forces a fallback; only degenerate shapes — empty tiles, or the
     un-pageable (n,) color/priority vectors busting the budget — take the
     jnp reference path."""
-    b = _resolve(backend)
+    b = _resolve("twohop", backend)
     R, W = ell_rows.shape
     n = colors.shape[0]
     n_all = ell_all.shape[0]
@@ -266,6 +304,7 @@ def twohop(ell_rows, ell_all, colors, pri, U_rows, row_start: int,
                 f"vectors are not pageable (degenerate shape)")
             b = "jnp"
     b = _forced_fallback("twohop", b)
+    _require_mosaic("twohop", b)
     _dispatched("twohop", b)
     if b == "jnp":
         return ref.twohop_ref(ell_rows, ell_all, colors, pri, row_start,
@@ -280,7 +319,7 @@ def ell_aggregate(ell, feats, op: str = "sum", backend: str = "auto", **kw):
     """GNN neighbor aggregation.  Falls back to jnp when the honest resident
     set (feature panel at its REAL width min(block_feats, d), not a
     hardcoded 128 lanes) busts the VMEM budget."""
-    b = _resolve(backend)
+    b = _resolve("ell_aggregate", backend)
     R, W = ell.shape
     n, d = feats.shape
     if b != "jnp":
@@ -295,6 +334,7 @@ def ell_aggregate(ell, feats, op: str = "sum", backend: str = "auto", **kw):
                 f"{_mb(need)} > {_mb(VMEM_BUDGET_BYTES)} budget")
             b = "jnp"
     b = _forced_fallback("ell_aggregate", b)
+    _require_mosaic("ell_aggregate", b)
     _dispatched("ell_aggregate", b)
     if b == "jnp":
         return ref.ell_spmm_ref(ell, feats, op)
@@ -303,7 +343,7 @@ def ell_aggregate(ell, feats, op: str = "sum", backend: str = "auto", **kw):
 
 
 def attention(q, k, v, *, causal: bool = True, backend: str = "auto", **kw):
-    b = _resolve(backend)
+    b = _resolve("attention", backend)
     _dispatched("attention", b)
     if b == "jnp":
         return ref.flash_attention_ref(q, k, v, causal=causal)

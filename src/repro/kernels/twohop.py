@@ -34,6 +34,8 @@ Hop-1 contributions (neighbor colors + the hop-1 defect test) are masked to
 the first page visit so they are counted once per row block, not once per
 page.  A vertex is always its own two-hop neighbor (v -> w -> v through any
 neighbor w); those slots are masked so a row never forbids its own color.
+
+Not lowerable for TPU yet: see ``ops.MOSAIC_UNSUPPORTED``.
 """
 from __future__ import annotations
 
@@ -130,7 +132,7 @@ def _twohop_kernel(ell_ref, page_ref, colors_ref, pri_ref, U_ref,
 def twohop_detect_recolor(ell_rows, ell_all, colors, pri, U_rows,
                           row_start: int, C: int = 64, block_rows: int = 128,
                           page_rows: int | None = None,
-                          interpret: bool = True):
+                          interpret: bool = False):
     """Fused two-hop pass for rows [row_start, row_start + R).
 
     ell_rows:  (R, W) neighbor tile for those rows

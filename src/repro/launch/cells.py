@@ -78,8 +78,7 @@ class Cell:
     static_notes: str = ""
 
     def lower(self, mesh: Mesh):
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else mesh:
+        with mesh:
             jitted = jax.jit(self.step, donate_argnums=self.donate)
             return jitted.lower(*self.abstract_args)
 
@@ -290,7 +289,7 @@ def _gnn_halo_train_cell(arch, shp_name, shp, mesh, arch_def,
     Shapes assume a block partition with ``boundary_frac`` of each shard's
     nodes on the boundary (mesh/ogb-class graphs; the real plan comes from
     core/partition.build_halo at run time)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     cfg = arch_def.make_full(d_in=shp["d_feat"], n_classes=shp["n_classes"])
     D = int(np.prod(list(mesh.shape.values())))
     axes = tuple(mesh.axis_names)
@@ -321,7 +320,7 @@ def _gnn_halo_train_cell(arch, shp_name, shp, mesh, arch_def,
         lambda p, b: local_loss(p, batch=b),
         mesh=mesh,
         in_specs=(P(), {k: shard for k in batch_abs}),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(

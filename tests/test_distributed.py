@@ -42,7 +42,7 @@ for gname, g in [("mesh2d", gen.mesh2d(24, 24)),
 # exactly equal -> no padding needed and the comparison is exact.
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.partition import block_partition, build_halo
 from repro.graphs.csr import from_edges, to_edge_list
@@ -88,7 +88,7 @@ shard = P("data")
 halo_loss = shard_map(
     lambda p, b: GNN.gatedgcn_halo_loss(p, cfg, b, ("data",), D),
     mesh=mesh, in_specs=(P(), {k: shard for k in batch}),
-    out_specs=P(), check_rep=False)
+    out_specs=P(), check_vma=False)
 lv = float(halo_loss(params, batch))
 e = to_edge_list(part.graph)
 logits = GNN.gatedgcn_apply(params, cfg, jnp.asarray(feats_g),

@@ -190,6 +190,28 @@ def test_ops_dispatch_jnp_cpu():
     np.testing.assert_array_equal(a[0], b[0])
 
 
+@pytest.mark.parametrize("kernel", ["firstfit", "detect_recolor", "twohop",
+                                    "ell_aggregate", "attention"])
+def test_auto_on_tpu_runs_mosaic_only_where_it_lowers(kernel, monkeypatch):
+    """On TPU ``auto`` picks Pallas only for kernels outside
+    ``MOSAIC_UNSUPPORTED``; a listed kernel runs the jnp reference and the
+    dispatch counter says so."""
+    from repro.obs import metrics
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    lowers = kernel not in ops.MOSAIC_UNSUPPORTED
+    assert ops.default_backend(kernel) == ("pallas" if lowers else "jnp")
+    if kernel != "firstfit":
+        return
+    before = metrics.counter_value("kernels.dispatch", kernel=kernel,
+                                   backend="jnp")
+    ell = jnp.asarray(_rand_ell(np.random.default_rng(1), 64, 8, 128))
+    colors = jnp.zeros((128,), jnp.int32)
+    a = ops.firstfit(ell, colors, C=32, backend="auto")
+    np.testing.assert_array_equal(a[0], ref.firstfit_ref(ell, colors, 32)[0])
+    assert metrics.counter_value("kernels.dispatch", kernel=kernel,
+                                 backend="jnp") == before + 1
+
+
 # --------------------------------------------------------------------------
 # VMEM paging boundary (DESIGN.md §8.3): table sizes straddling the old 8 MB
 # residency bound stay on the Pallas path — zero kernels.fallback — and match
