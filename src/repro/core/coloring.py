@@ -57,6 +57,14 @@ from repro.resilience.errors import CapRetryExhausted
 
 MAX_ROUNDS_TRACE = 64  # fixed-size conflict trace (while_loop-friendly)
 
+# ``jax.named_scope`` names of the static solve's device work: the neighbor
+# gather and defect test, the forbidden set and mex with the write-back, the
+# COO overflow snapshot and its defect test, and the two loops around them.
+# Scopes set HLO ``op_name`` metadata only, never instructions or fusion, so
+# they stay on with tracing off; a device profile files each op under the
+# scopes on its path (DESIGN.md §12.1).
+SOLVE_SCOPES = ("gather", "mex", "overflow", "round0", "repair")
+
 # back-compat alias: the canonical definition moved to core/context.py with
 # the PassContext it configures (DESIGN.md §11)
 _resolve_impl = resolve_impl
@@ -197,34 +205,42 @@ def _pick_C(g: CSRGraph, C: Optional[int]) -> int:
 def prepare(g: CSRGraph, seed: int = 0, n_chunks: int = 16,
             ell_cap: int = 512, C: Optional[int] = None,
             relabel: bool = True) -> ColoringProblem:
+    """Relabel, lay out and upload ``g``: three traced phases
+    (``prepare.relabel|layout|upload``) nested in the caller's ``prepare``.
+    Under a tracer the upload blocks on the copies, so its time is theirs."""
     n = g.n_vertices
     rng = np.random.default_rng(seed)
-    perm = rng.permutation(n).astype(np.int64) if relabel else np.arange(n)
-    if relabel:
-        edges = perm[to_edge_list(g).astype(np.int64)]
-        g = from_edges(n, edges, symmetrize=False)
-    n_pad = -(-max(n, n_chunks) // n_chunks) * n_chunks
-    W = max(1, min(g.max_degree, ell_cap))
-    deg = g.degrees
-    if g.max_degree <= ell_cap:
-        ell = to_ell(g, max_degree=W, pad_vertices_to=n_pad)
-        osrc = np.zeros((0,), np.int32)
-        odst = np.zeros((0,), np.int32)
-    else:
-        ell = np.full((n_pad, W), FILL, dtype=np.int32)
-        row = np.repeat(np.arange(n), deg)
-        col = np.arange(g.n_edges) - np.repeat(g.indptr[:-1], deg)
-        in_ell = col < W
-        ell[row[in_ell], col[in_ell]] = g.indices[in_ell]
-        osrc = row[~in_ell].astype(np.int32)
-        odst = g.indices[~in_ell].astype(np.int32)
-    # independent random priorities (asymmetric tie-break)
-    pri = np.full(n_pad, -1, np.int32)
-    pri[:n] = rng.permutation(n).astype(np.int32)
-    return ColoringProblem(
-        ell=jnp.asarray(ell), ovf_src=jnp.asarray(osrc),
-        ovf_dst=jnp.asarray(odst), pri=jnp.asarray(pri),
-        n=n, n_pad=n_pad, perm=perm, C=_pick_C(g, C))
+    with obs.phase("prepare.relabel"):
+        perm = (rng.permutation(n).astype(np.int64) if relabel
+                else np.arange(n))
+        if relabel:
+            edges = perm[to_edge_list(g).astype(np.int64)]
+            g = from_edges(n, edges, symmetrize=False)
+    with obs.phase("prepare.layout"):
+        n_pad = -(-max(n, n_chunks) // n_chunks) * n_chunks
+        W = max(1, min(g.max_degree, ell_cap))
+        deg = g.degrees
+        if g.max_degree <= ell_cap:
+            ell = to_ell(g, max_degree=W, pad_vertices_to=n_pad)
+            osrc = np.zeros((0,), np.int32)
+            odst = np.zeros((0,), np.int32)
+        else:
+            ell = np.full((n_pad, W), FILL, dtype=np.int32)
+            row = np.repeat(np.arange(n), deg)
+            col = np.arange(g.n_edges) - np.repeat(g.indptr[:-1], deg)
+            in_ell = col < W
+            ell[row[in_ell], col[in_ell]] = g.indices[in_ell]
+            osrc = row[~in_ell].astype(np.int32)
+            odst = g.indices[~in_ell].astype(np.int32)
+        # independent random priorities (asymmetric tie-break)
+        pri = np.full(n_pad, -1, np.int32)
+        pri[:n] = rng.permutation(n).astype(np.int32)
+        C = _pick_C(g, C)
+    with obs.phase("prepare.upload"):
+        dev = tuple(jnp.asarray(a) for a in (ell, osrc, odst, pri))
+        if obs.current_tracer() is not None:
+            jax.block_until_ready(dev)
+    return ColoringProblem(*dev, n=n, n_pad=n_pad, perm=perm, C=C)
 
 
 def _unpermute(colors_new: np.ndarray, perm: np.ndarray, n: int) -> np.ndarray:
@@ -337,15 +353,17 @@ def _chunked_pass(ctx, ell, osrc, odst, pri, colors, U, force, *,
     cs = n_pad // n_chunks
     valid_row = jnp.arange(n_pad) < n if valid is None else valid
     has_ovf = osrc.shape[0] > 0
-    snap_forb = (_snapshot_coo(osrc, odst, colors, n_pad, C, impl)
-                 if has_ovf else None)
-    # overflow-edge conflicts, evaluated once on the pass-start snapshot.
-    # (Conflicts only ever arise between two vertices recolored in the same
-    # earlier pass, so the snapshot view is sufficient for detection; see
-    # module docstring termination argument.)
-    ovf_defect = None
-    if has_ovf and detect:
-        ovf_defect = _ovf_conflict(osrc, odst, colors, pri, n_pad)
+    snap_forb = ovf_defect = None
+    if has_ovf:
+        with jax.named_scope("overflow"):
+            snap_forb = _snapshot_coo(osrc, odst, colors, n_pad, C, impl)
+            # overflow-edge conflicts, evaluated once on the pass-start
+            # snapshot.  (Conflicts only ever arise between two vertices
+            # recolored in the same earlier pass, so the snapshot view is
+            # sufficient for detection; see module docstring termination
+            # argument.)
+            if detect:
+                ovf_defect = _ovf_conflict(osrc, odst, colors, pri, n_pad)
 
     def chunk_body(k, carry):
         colors, recolored, n_def, ovf = carry
@@ -356,25 +374,28 @@ def _chunked_pass(ctx, ell, osrc, odst, pri, colors, U, force, *,
         valid_k = jax.lax.dynamic_slice_in_dim(valid_row, lo, cs, 0)
         c_k = jax.lax.dynamic_slice_in_dim(colors, lo, cs, 0)
         pri_k = jax.lax.dynamic_slice_in_dim(pri, lo, cs, 0)
-        nbrc, nbrp = _gather_nbr(ell_k, colors, pri)          # FRESH colors
-        if detect:
-            defect = ((nbrc == c_k[:, None]) & (c_k[:, None] >= 0)
-                      & (nbrp > pri_k[:, None])).any(axis=1)
-            if ovf_defect is not None:
-                defect = defect | jax.lax.dynamic_slice_in_dim(
-                    ovf_defect, lo, cs, 0)
-            work = valid_k & ((U_k & defect) | force_k)
-            n_def = n_def + (valid_k & U_k & defect).sum(dtype=jnp.int32)
-        else:
-            work = valid_k & (U_k | force_k)
-        forb = _forbidden(nbrc, C, impl)
-        if has_ovf:
-            sf_k = jax.lax.dynamic_slice_in_dim(snap_forb, lo, cs, 0)
-            forb = _merge_forbidden(forb, sf_k, impl)
-        mex, ovf_k = _mex_of(forb, C, impl)
-        newc = jnp.where(work, mex, c_k)
-        colors = jax.lax.dynamic_update_slice_in_dim(colors, newc, lo, 0)
-        recolored = jax.lax.dynamic_update_slice_in_dim(recolored, work, lo, 0)
+        with jax.named_scope("gather"):
+            nbrc, nbrp = _gather_nbr(ell_k, colors, pri)      # FRESH colors
+            if detect:
+                defect = ((nbrc == c_k[:, None]) & (c_k[:, None] >= 0)
+                          & (nbrp > pri_k[:, None])).any(axis=1)
+                if ovf_defect is not None:
+                    defect = defect | jax.lax.dynamic_slice_in_dim(
+                        ovf_defect, lo, cs, 0)
+                work = valid_k & ((U_k & defect) | force_k)
+                n_def = n_def + (valid_k & U_k & defect).sum(dtype=jnp.int32)
+            else:
+                work = valid_k & (U_k | force_k)
+        with jax.named_scope("mex"):
+            forb = _forbidden(nbrc, C, impl)
+            if has_ovf:
+                sf_k = jax.lax.dynamic_slice_in_dim(snap_forb, lo, cs, 0)
+                forb = _merge_forbidden(forb, sf_k, impl)
+            mex, ovf_k = _mex_of(forb, C, impl)
+            newc = jnp.where(work, mex, c_k)
+            colors = jax.lax.dynamic_update_slice_in_dim(colors, newc, lo, 0)
+            recolored = jax.lax.dynamic_update_slice_in_dim(recolored, work,
+                                                            lo, 0)
         return colors, recolored, n_def, ovf | (ovf_k & work).any()
 
     init = (colors, jnp.zeros((n_pad,), bool), jnp.int32(0), jnp.bool_(False))
@@ -385,11 +406,13 @@ def _detect_pass(ctx, ell, osrc, odst, pri, colors, U):
     """CAT phase B: standalone defect detection over U (full gather pass)."""
     n, n_pad, C, n_chunks, impl = ctx.unpack()
     valid_row = jnp.arange(n_pad) < n
-    nbrc, nbrp = _gather_nbr(ell, colors, pri)
-    defect = ((nbrc == colors[:, None]) & (colors[:, None] >= 0)
-              & (nbrp > pri[:, None])).any(axis=1)
+    with jax.named_scope("gather"):
+        nbrc, nbrp = _gather_nbr(ell, colors, pri)
+        defect = ((nbrc == colors[:, None]) & (colors[:, None] >= 0)
+                  & (nbrp > pri[:, None])).any(axis=1)
     if osrc.shape[0] > 0:
-        defect = defect | _ovf_conflict(osrc, odst, colors, pri, n_pad)
+        with jax.named_scope("overflow"):
+            defect = defect | _ovf_conflict(osrc, odst, colors, pri, n_pad)
     return defect & U & valid_row
 
 
@@ -446,7 +469,8 @@ def _fused_repair(ctx, ell, osrc, odst, pri, colors, U, max_rounds,
             if ctx.trace else (colors, U, trace))
     state = head + (jnp.int32(0), jnp.int32(0), jnp.int32(1),
                     jnp.bool_(ovf0))
-    out = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("repair"):
+        out = jax.lax.while_loop(cond, body, state)
     if ctx.trace:
         colors, U, trace, ftrace, r, tot, _, ovf = out
         return colors, r, trace, ftrace, tot, ovf
@@ -462,8 +486,9 @@ def _rsoc_loop(ell, osrc, odst, pri, ctx, max_rounds):
     zeros = jnp.zeros((n_pad,), bool)
 
     # round 0: tentative coloring of the whole graph (chunked, fresh)
-    colors1, U, _, ovf0 = _chunked_pass(
-        ctx, ell, osrc, odst, pri, colors0, zeros, valid, detect=False)
+    with jax.named_scope("round0"):
+        colors1, U, _, ovf0 = _chunked_pass(
+            ctx, ell, osrc, odst, pri, colors0, zeros, valid, detect=False)
     out = _fused_repair(
         ctx, ell, osrc, odst, pri, colors1, U, max_rounds, ovf0)
     return (out[0][:n],) + out[1:]
@@ -482,11 +507,12 @@ def _cat_loop(ell, osrc, odst, pri, ctx, max_rounds):
     valid = jnp.arange(n_pad) < n
     zeros = jnp.zeros((n_pad,), bool)
 
-    # round 0 phase A: color everything (chunked, fresh within pass)
-    colors1, _, _, ovf0 = _chunked_pass(
-        ctx, ell, osrc, odst, pri, colors0, zeros, valid, detect=False)
-    # round 0 phase B: detect                                   (pass 2)
-    U1 = _detect_pass(ctx, ell, osrc, odst, pri, colors1, valid)
+    with jax.named_scope("round0"):
+        # round 0 phase A: color everything (chunked, fresh within pass)
+        colors1, _, _, ovf0 = _chunked_pass(
+            ctx, ell, osrc, odst, pri, colors0, zeros, valid, detect=False)
+        # round 0 phase B: detect                               (pass 2)
+        U1 = _detect_pass(ctx, ell, osrc, odst, pri, colors1, valid)
 
     def cond(s):
         return s[1].any() & (s[3] < max_rounds)
@@ -504,7 +530,8 @@ def _cat_loop(ell, osrc, odst, pri, ctx, max_rounds):
 
     trace = jnp.zeros((MAX_ROUNDS_TRACE,), jnp.int32)
     state = (colors1, U1, trace, jnp.int32(0), jnp.int32(0), ovf0)
-    colors, U, trace, r, tot, ovf = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("repair"):
+        colors, U, trace, r, tot, ovf = jax.lax.while_loop(cond, body, state)
     return colors[:n], r, trace, tot, ovf
 
 
@@ -644,14 +671,16 @@ def _rsoc_engine(g: CSRGraph, spec) -> ColoringResult:
         _prob_runner(_rsoc_loop, prob, spec.n_chunks, spec.max_rounds, impl,
                      trace=tracer is not None),
         prob.C, engine="rsoc", max_retries=spec.max_cap_retries)
-    colors, r, trace, ftrace, tot = _loop_outputs(out, tracer is not None)
-    _report_frontier(tracer, ftrace, r)
-    conf, truncated = _trim_trace(trace, r)
-    colors = _unpermute(colors, prob.perm, prob.n)
+    with obs.phase("finish"):
+        colors, r, trace, ftrace, tot = _loop_outputs(out, tracer is not None)
+        _report_frontier(tracer, ftrace, r)
+        conf, truncated = _trim_trace(trace, r)
+        colors = _unpermute(colors, prob.perm, prob.n)
+        n_colors = n_colors_used(colors)
     return ColoringResult(colors=colors, n_rounds=int(r),
                           conflicts_per_round=conf,
                           total_conflicts=int(tot),
-                          n_colors=n_colors_used(colors),
+                          n_colors=n_colors,
                           overflow=retries > 0,
                           gather_passes=1 + int(r),
                           final_C=final_C, retries=retries,
@@ -670,16 +699,19 @@ def _cat_engine(g: CSRGraph, spec) -> ColoringResult:
     (colors, r, trace, tot, _), final_C, retries = _run_with_retry(
         _prob_runner(_cat_loop, prob, spec.n_chunks, spec.max_rounds, impl),
         prob.C, engine="cat", max_retries=spec.max_cap_retries)
-    conf, truncated = _trim_trace(trace, r)
-    # CAT's frontier IS its conflict count: a round re-colors exactly the
-    # defect set U detected by the previous phase B, so no extra device
-    # collection is needed (the traced and untraced programs are identical).
-    _report_frontier(tracer, conf, r)
-    colors = _unpermute(colors, prob.perm, prob.n)
+    with obs.phase("finish"):
+        conf, truncated = _trim_trace(trace, r)
+        # CAT's frontier IS its conflict count: a round re-colors exactly
+        # the defect set U detected by the previous phase B, so no extra
+        # device collection is needed (the traced and untraced programs are
+        # identical).
+        _report_frontier(tracer, conf, r)
+        colors = _unpermute(colors, prob.perm, prob.n)
+        n_colors = n_colors_used(colors)
     return ColoringResult(colors=colors, n_rounds=int(r),
                           conflicts_per_round=conf,
                           total_conflicts=int(tot),
-                          n_colors=n_colors_used(colors),
+                          n_colors=n_colors,
                           overflow=retries > 0,
                           gather_passes=2 * (1 + int(r)),
                           final_C=final_C, retries=retries,

@@ -64,7 +64,7 @@ class PhaseEvent:
     """One host-timed phase (the timer brackets a jit boundary: the engine
     blocks on the phase's outputs before the timer stops)."""
 
-    name: str             # prepare | solve | serial_repair | ...
+    name: str             # prepare | prepare.relabel | solve | finish | ...
     wall_s: float
     meta: dict = dataclasses.field(default_factory=dict)   # e.g. C, attempt
 
@@ -135,14 +135,21 @@ class RunTracer:
         outputs (``jax.block_until_ready`` / host conversion) for the timer
         to mean anything; the standard call sites do.  Also opens a
         ``jax.profiler`` annotation scope so device profiles show the same
-        phase names (``obs.export.annotate``)."""
+        phase names (``obs.export.annotate``).
+
+        The event is recorded even when the body raises.  A phase opened
+        inside another (``prepare.relabel`` inside ``prepare``) is an event
+        of its own, under its own name, so a total by name
+        (``RunTrace.phase_wall_s``) never counts a nested phase twice."""
         from repro.obs.export import annotate
         t0 = time.perf_counter()
-        with annotate(f"repro.{name}"):
-            yield
-        self._phases.append(PhaseEvent(name=name,
-                                       wall_s=time.perf_counter() - t0,
-                                       meta=dict(meta)))
+        try:
+            with annotate(f"repro.{name}"):
+                yield
+        finally:
+            self._phases.append(PhaseEvent(name=name,
+                                           wall_s=time.perf_counter() - t0,
+                                           meta=dict(meta)))
 
     def set_frontier_trace(self, frontier, cap: Optional[int] = None) -> None:
         """Per-round |U| counts from the loop carry (engines that collect

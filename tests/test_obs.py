@@ -16,6 +16,7 @@ Covers the acceptance criteria of the obs PR:
     batch) are asserted through the new memo counters.
 """
 import json
+import re
 import warnings
 
 import numpy as np
@@ -145,6 +146,71 @@ def test_untraced_loop_is_pre_obs_program():
                                prob.pri, mk(True), cap, 100)
     assert len(off) == 5 and len(on) == 6
     np.testing.assert_array_equal(np.asarray(off[0]), np.asarray(on[0]))
+
+
+PREPARE_PARTS = ("prepare.relabel", "prepare.layout", "prepare.upload")
+
+
+@pytest.mark.parametrize("algo", ["rsoc", "cat"])
+def test_traced_call_phases_nest_in_prepare(algo, monkeypatch):
+    """A traced static call times relabel, layout and upload inside
+    ``prepare``, and the work after the solve as ``finish``; a nested phase
+    is an event of its own, so the total by name keeps its meaning."""
+    _no_env_trace(monkeypatch)
+    with obs.trace() as tc:
+        api.color(MESH, algorithm=algo, seed=3)
+    t = tc.traces[0]
+    by_name = {}
+    for p in t.phases:
+        by_name.setdefault(p.name, []).append(p.wall_s)
+    for name in PREPARE_PARTS + ("prepare", "solve", "finish"):
+        assert len(by_name.get(name, ())) == 1, (name, by_name)
+        assert by_name[name][0] >= 0
+    assert t.phase_wall_s("prepare") == by_name["prepare"][0]
+    assert sum(t.phase_wall_s(n) for n in PREPARE_PARTS) <= \
+        t.phase_wall_s("prepare")
+
+
+def test_phase_event_recorded_when_body_raises():
+    from repro.obs.trace import RunTracer
+    tracer = RunTracer()
+    with pytest.raises(ZeroDivisionError):
+        with tracer.phase("prepare", attempt=0):
+            with tracer.phase("prepare.relabel"):
+                1 / 0
+    names = [p.name for p in tracer._phases]
+    assert names == ["prepare.relabel", "prepare"]
+    assert tracer._phases[1].meta == {"attempt": 0}
+    assert all(p.wall_s >= 0 for p in tracer._phases)
+
+
+def _hub_graph():
+    from repro.graphs.csr import from_edges
+    edges = [(0, v) for v in range(1, 41)] + [(v, v + 1) for v in range(1, 40)]
+    return from_edges(41, np.asarray(edges))
+
+
+def _loop_hlo(g, ell_cap):
+    prob = col.prepare(g, 3, 4, ell_cap)
+    ctx = PassContext.for_problem(prob, n_chunks=4, C=prob.C,
+                                  forbidden_impl="bitset")
+    return prob, col._rsoc_loop.lower(prob.ell, prob.ovf_src, prob.ovf_dst,
+                                      prob.pri, ctx, 100).compile().as_text()
+
+
+@pytest.mark.parametrize("case", ["mesh", "hub"])
+def test_solve_scopes_name_the_untraced_loop(case):
+    """The untraced ``_rsoc_loop`` carries the solve's named scopes in its
+    ``op_name`` metadata, ``overflow`` only where hubs pass ``ell_cap``."""
+    g, cap = (MESH, 512) if case == "mesh" else (_hub_graph(), 8)
+    prob, hlo = _loop_hlo(g, cap)
+    assert (prob.ovf_src.shape[0] > 0) == (case == "hub")
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    scoped = {part for n in names for part in n.split("/")[:-1]}
+    want = {"gather", "mex", "round0", "repair"}
+    assert want <= scoped, scoped
+    assert ("overflow" in scoped) == (case == "hub")
+    assert set(col.SOLVE_SCOPES) == want | {"overflow"}
 
 
 # --------------------------------------------------------------------------
