@@ -20,7 +20,9 @@ Vocabulary of the TPU adaptation (DESIGN.md §2):
     repaired the moment it is seen, from the same gathered neighbor row
     ("freshest data", paper §3).  One gather pass, one materialization point.
     Repairs land a round earlier than CAT's, so rounds and conflicts drop —
-    the paper's Figs. 3-6 mechanism.
+    the paper's Figs. 3-6 mechanism.  A round whose U is at most half the
+    rows gathers only U's rows, chunk by chunk, with the same result bit
+    for bit (``_compact_chunk_pass``, DESIGN.md §2).
   * Termination under lockstep (paper §5: SIMT livelock): conflicts are broken
     *asymmetrically* by a hashed random priority — of a conflicting edge only
     the lower-priority endpoint re-colors.  Every round the highest-priority
@@ -335,6 +337,25 @@ def _forbidden_from_nbrc(nbrc, C):
     return forb.at[r, jnp.clip(nbrc, 0, C - 1)].max(ok.astype(jnp.uint8))
 
 
+def _pass_snapshot(ctx, osrc, odst, pri, colors, detect):
+    """Pass-start COO overflow tables ``(snap_forb, ovf_defect)`` over all
+    ``n_pad`` rows, each None where there is no overflow (``ovf_defect``
+    also without ``detect``)."""
+    if osrc.shape[0] == 0:
+        return None, None
+    n, n_pad, C, n_chunks, impl = ctx.unpack()
+    with jax.named_scope("overflow"):
+        snap_forb = _snapshot_coo(osrc, odst, colors, n_pad, C, impl)
+        # overflow-edge conflicts, evaluated once on the pass-start
+        # snapshot.  (Conflicts only ever arise between two vertices
+        # recolored in the same earlier pass, so the snapshot view is
+        # sufficient for detection; see module docstring termination
+        # argument.)
+        ovf_defect = (_ovf_conflict(osrc, odst, colors, pri, n_pad)
+                      if detect else None)
+    return snap_forb, ovf_defect
+
+
 def _chunked_pass(ctx, ell, osrc, odst, pri, colors, U, force, *,
                   detect: bool, valid=None):
     """One sequential sweep over n_chunks chunks.
@@ -353,17 +374,8 @@ def _chunked_pass(ctx, ell, osrc, odst, pri, colors, U, force, *,
     cs = n_pad // n_chunks
     valid_row = jnp.arange(n_pad) < n if valid is None else valid
     has_ovf = osrc.shape[0] > 0
-    snap_forb = ovf_defect = None
-    if has_ovf:
-        with jax.named_scope("overflow"):
-            snap_forb = _snapshot_coo(osrc, odst, colors, n_pad, C, impl)
-            # overflow-edge conflicts, evaluated once on the pass-start
-            # snapshot.  (Conflicts only ever arise between two vertices
-            # recolored in the same earlier pass, so the snapshot view is
-            # sufficient for detection; see module docstring termination
-            # argument.)
-            if detect:
-                ovf_defect = _ovf_conflict(osrc, odst, colors, pri, n_pad)
+    snap_forb, ovf_defect = _pass_snapshot(ctx, osrc, odst, pri, colors,
+                                           detect)
 
     def chunk_body(k, carry):
         colors, recolored, n_def, ovf = carry
@@ -402,6 +414,103 @@ def _chunked_pass(ctx, ell, osrc, odst, pri, colors, U, force, *,
     return jax.lax.fori_loop(0, n_chunks, chunk_body, init)
 
 
+# The compacted repair pass gathers ELL rows in blocks of B rows, B the
+# power of two with B * W in [2^15, 2^16) elements (W the ELL width), capped
+# at the chunk: a few hundred KB of gathered colors and priorities a block,
+# whatever the graph's degree.
+_BLOCK_ELEMS = 1 << 15
+
+
+def _repair_block(W: int, cs: int) -> int:
+    """Rows per block of ``_compact_chunk_pass`` for ELL width ``W`` and
+    chunk size ``cs``."""
+    return min(1 << (-(-_BLOCK_ELEMS // W) - 1).bit_length(), cs)
+
+
+def _compact_cap(n_pad: int) -> int:
+    """The largest frontier a repair round gathers compacted; a larger one
+    takes the full-width pass.  With every row in the frontier, on one
+    v5e chip, the compacted pass took about 0.50 s on RMAT-B 2^16 against
+    0.393 s at full width, and about 0.70 s on RMAT-ER 2^20 against
+    1.158 s (PERF.md §6): which wins depends on the graph, not on the
+    count, and the full-width pass keeps the round that holds most rows."""
+    return n_pad // 2
+
+
+def _compact_chunk_pass(ctx, ell, osrc, odst, pri, colors, U, force):
+    """``_chunked_pass(detect=True)`` that gathers only the rows of ``U``.
+
+    Same schedule, same result bit for bit: chunk k still covers rows
+    [k*cs, (k+1)*cs), the chunks run in order, and every frontier row of
+    chunk k is judged against the colors as they stood at the start of
+    chunk k.  A row outside ``U`` can never change there (``force`` lies in
+    ``U``), so it is not gathered.  Chunk k visits its own frontier rows in
+    ``ceil(count_k / B)`` blocks of B rows (``_repair_block``), none when
+    it has none; each block reads the chunk-start colors and writes a
+    chunk-local buffer that is committed when the chunk ends.  The overflow
+    tables are the full pass's pass-start snapshot, read by row.  Returns
+    (colors, recolored_mask, n_defects, overflowed, rows): ``rows`` is the
+    ELL rows the blocks gathered, ``B`` times their count (dead code unless
+    a traced loop keeps it).
+    """
+    n, n_pad, C, n_chunks, impl = ctx.unpack()
+    cs = n_pad // n_chunks
+    B = _repair_block(ell.shape[1], cs)
+    snap_forb, ovf_defect = _pass_snapshot(ctx, osrc, odst, pri, colors,
+                                           True)
+    counts = U.reshape(n_chunks, cs).sum(axis=1, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    # the rows of U in ascending order, so chunk k's are
+    # idx[starts[k]:starts[k] + counts[k]]; B tail slots keep every block's
+    # slice in range
+    idx = jnp.nonzero(U, size=n_pad + B, fill_value=n_pad)[0].astype(
+        jnp.int32)
+
+    def chunk_body(k, carry):
+        colors_k0, recolored, n_def, ovf = carry
+        lo = k * cs
+
+        def block_body(b, bcarry):
+            buf, recolored, n_def, ovf = bcarry
+            ids = jax.lax.dynamic_slice_in_dim(idx, starts[k] + b * B, B, 0)
+            # slots past the chunk's count hold n_pad: the scatters drop them
+            ids = jnp.where(b * B + jnp.arange(B) < counts[k], ids, n_pad)
+            rows = jnp.minimum(ids, n_pad - 1)
+            with jax.named_scope("gather"):
+                c_b = colors_k0[rows]
+                nbrc, nbrp = _gather_nbr(ell[rows], colors_k0, pri)
+                defect = ((nbrc == c_b[:, None]) & (c_b[:, None] >= 0)
+                          & (nbrp > pri[rows][:, None])).any(axis=1)
+            if ovf_defect is not None:
+                with jax.named_scope("overflow"):
+                    defect = defect | ovf_defect[rows]
+                    sf_b = snap_forb[rows]
+            with jax.named_scope("gather"):
+                valid_b = ids < n
+                work = valid_b & (defect | force[rows])
+                n_def = n_def + (valid_b & defect).sum(dtype=jnp.int32)
+            with jax.named_scope("mex"):
+                forb = _forbidden(nbrc, C, impl)
+                if ovf_defect is not None:
+                    forb = _merge_forbidden(forb, sf_b, impl)
+                mex, ovf_b = _mex_of(forb, C, impl)
+                buf = buf.at[ids - lo].set(jnp.where(work, mex, c_b),
+                                           mode="drop")
+                recolored = recolored.at[ids].set(work, mode="drop")
+            return buf, recolored, n_def, ovf | (ovf_b & work).any()
+
+        n_blocks = (counts[k] + B - 1) // B
+        buf0 = jax.lax.dynamic_slice_in_dim(colors_k0, lo, cs, 0)
+        buf, recolored, n_def, ovf = jax.lax.fori_loop(
+            0, n_blocks, block_body, (buf0, recolored, n_def, ovf))
+        colors = jax.lax.dynamic_update_slice_in_dim(colors_k0, buf, lo, 0)
+        return colors, recolored, n_def, ovf
+
+    init = (colors, jnp.zeros((n_pad,), bool), jnp.int32(0), jnp.bool_(False))
+    out = jax.lax.fori_loop(0, n_chunks, chunk_body, init)
+    return out + (((counts + B - 1) // B * B).sum(),)
+
+
 def _detect_pass(ctx, ell, osrc, odst, pri, colors, U):
     """CAT phase B: standalone defect detection over U (full gather pass)."""
     n, n_pad, C, n_chunks, impl = ctx.unpack()
@@ -428,12 +537,16 @@ def _fused_repair(ctx, ell, osrc, odst, pri, colors, U, max_rounds,
     caller (incremental recoloring, distributed shards) can supply its own
     seed set U and partial coloring.  Vertices in U are re-colored only when
     defective *right now*; uncolored seeds (colors < 0) are force-colored on
-    their first pass.  Returns (colors, n_rounds, trace, total_defects, ovf)
-    — one neighbor-gather pass per round — or, under the static
-    ``ctx.trace`` flag, (colors, n_rounds, trace, ftrace, total_defects,
-    ovf) with a per-round |U| trace spliced in BEFORE the trailing pair so
-    the retry contract (overflow flag last) survives.  ``ctx.trace`` is a
-    jit-cache key: the False program is exactly the pre-obs one.
+    their first pass.  A round whose frontier is at most half the rows
+    gathers only the frontier's rows (``_compact_chunk_pass``), any other
+    the full width (``_chunked_pass``): the two give the same colors bit for
+    bit.  Returns (colors, n_rounds, trace, total_defects, ovf) — one
+    neighbor-gather pass per round — or, under the static ``ctx.trace``
+    flag, (colors, n_rounds, trace, ftrace, total_defects, ovf) with a
+    per-round (2, MAX_ROUNDS_TRACE) trace, |U| and the ELL rows gathered,
+    spliced in BEFORE the trailing pair so the retry contract (overflow
+    flag last) survives.  ``ctx.trace`` is a jit-cache key: the False
+    program carries neither.
     """
     n, n_pad, C, n_chunks, impl = ctx.unpack()
 
@@ -447,14 +560,18 @@ def _fused_repair(ctx, ell, osrc, odst, pri, colors, U, max_rounds,
     def body(s):
         if ctx.trace:
             colors, U, trace, ftrace, r, tot, last_def, ovf = s
-            ftrace = ftrace.at[jnp.minimum(r, MAX_ROUNDS_TRACE - 1)].set(
-                U.sum(dtype=jnp.int32))
         else:
             colors, U, trace, r, tot, last_def, ovf = s
         force = U & (colors < 0)
-        # ONE fused detect-and-recolor pass
-        colors2, recolored, n_def, ovf2 = _chunked_pass(
-            ctx, ell, osrc, odst, pri, colors, U, force, detect=True)
+        # ONE fused detect-and-recolor pass; ``rows``: what it gathered
+        args = (ctx, ell, osrc, odst, pri, colors, U, force)
+        colors2, recolored, n_def, ovf2, rows = jax.lax.cond(
+            U.sum(dtype=jnp.int32) > _compact_cap(n_pad),
+            lambda: _chunked_pass(*args, detect=True) + (jnp.int32(n_pad),),
+            lambda: _compact_chunk_pass(*args))
+        if ctx.trace:
+            ftrace = ftrace.at[:, jnp.minimum(r, MAX_ROUNDS_TRACE - 1)].set(
+                jnp.stack([U.sum(dtype=jnp.int32), rows]))
         trace = trace.at[jnp.minimum(r, MAX_ROUNDS_TRACE - 1)].set(n_def)
         # forced vertices were colored speculatively, not verified: keep the
         # loop alive so the next pass checks them (two adjacent uncolored
@@ -465,7 +582,7 @@ def _fused_repair(ctx, ell, osrc, odst, pri, colors, U, max_rounds,
         return head + (r + 1, tot + n_def, n_work, ovf | ovf2)
 
     trace = jnp.zeros((MAX_ROUNDS_TRACE,), jnp.int32)
-    head = ((colors, U, trace, jnp.zeros((MAX_ROUNDS_TRACE,), jnp.int32))
+    head = ((colors, U, trace, jnp.zeros((2, MAX_ROUNDS_TRACE), jnp.int32))
             if ctx.trace else (colors, U, trace))
     state = head + (jnp.int32(0), jnp.int32(0), jnp.int32(1),
                     jnp.bool_(ovf0))
@@ -496,7 +613,7 @@ def _rsoc_loop(ell, osrc, odst, pri, ctx, max_rounds):
 
 @functools.partial(jax.jit, static_argnames=("ctx", "max_rounds"))
 def _rsoc_repair_loop(ell, osrc, odst, pri, colors, U, ctx, max_rounds):
-    """Externally-seeded fused repair (full-width passes; no round 0)."""
+    """Externally-seeded fused repair (no round 0)."""
     return _fused_repair(ctx, ell, osrc, odst, pri, colors, U, max_rounds)
 
 
@@ -645,13 +762,17 @@ def _loop_outputs(out, traced: bool):
     return colors, r, trace, None, tot
 
 
-def _report_frontier(tracer, ftrace, r, cap=None):
+def _report_frontier(tracer, ftrace, r, cap=None, n_pad=None):
     """Hand a loop-carried frontier trace to the tracer, clipped like the
-    conflict trace is."""
+    conflict trace is.  A 2-row trace (``_fused_repair``'s) holds |U| and
+    the ELL rows gathered per round, out of ``n_pad``."""
     if tracer is not None and ftrace is not None:
-        trimmed = np.asarray(ftrace).reshape(-1)[
-            :min(int(r), MAX_ROUNDS_TRACE)]
-        tracer.set_frontier_trace(trimmed, cap=cap)
+        trimmed = np.asarray(ftrace)[..., :min(int(r), MAX_ROUNDS_TRACE)]
+        if trimmed.ndim == 2:
+            tracer.set_frontier_trace(trimmed[0], cap=cap, rows=trimmed[1],
+                                      n_pad=n_pad)
+        else:
+            tracer.set_frontier_trace(trimmed, cap=cap)
 
 
 # --------------------------------------------------------------------------
@@ -673,7 +794,8 @@ def _rsoc_engine(g: CSRGraph, spec) -> ColoringResult:
         prob.C, engine="rsoc", max_retries=spec.max_cap_retries)
     with obs.phase("finish"):
         colors, r, trace, ftrace, tot = _loop_outputs(out, tracer is not None)
-        _report_frontier(tracer, ftrace, r)
+        _report_frontier(tracer, ftrace, r, cap=_compact_cap(prob.n_pad),
+                         n_pad=prob.n_pad)
         conf, truncated = _trim_trace(trace, r)
         colors = _unpermute(colors, prob.perm, prob.n)
         n_colors = n_colors_used(colors)

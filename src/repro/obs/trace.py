@@ -57,6 +57,8 @@ class RoundEvent:
     frontier: int = -1    # |U| at round start (-1: engine does not collect)
     compacted: Optional[bool] = None   # frontier-compacted engines only:
     #                                    did this round take the small pass?
+    rows: int = -1        # ELL rows the round's pass gathered (-1: engine
+    #                       does not collect)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +88,8 @@ class RunTrace:
     n_colors: int
     truncated: bool               # rounds beyond MAX_ROUNDS_TRACE collapsed
     wall_s: float                 # whole engine call, host-side
+    n_pad: int = -1               # padded rows, the most a pass gathers
+    #                               (-1: engine does not report ``rows``)
 
     @property
     def conflicts_per_round(self) -> np.ndarray:
@@ -127,6 +131,8 @@ class RunTracer:
         self._phases: list[PhaseEvent] = []
         self._frontier: Optional[np.ndarray] = None
         self._compact_cap: Optional[int] = None
+        self._rows: Optional[np.ndarray] = None
+        self._n_pad = -1
         self._t0 = time.perf_counter()
 
     @contextlib.contextmanager
@@ -151,27 +157,35 @@ class RunTracer:
                                            wall_s=time.perf_counter() - t0,
                                            meta=dict(meta)))
 
-    def set_frontier_trace(self, frontier, cap: Optional[int] = None) -> None:
+    def set_frontier_trace(self, frontier, cap: Optional[int] = None,
+                           rows=None, n_pad: Optional[int] = None) -> None:
         """Per-round |U| counts from the loop carry (engines that collect
-        them under the static ``ctx.trace`` flag).  ``cap``: the compacted
-        frontier capacity, when the engine has one — lets the round events
-        say whether the round took the compacted or the full-width pass."""
+        them under the static ``ctx.trace`` flag).  ``cap``: the largest
+        frontier the engine compacts, when it has one — lets the round
+        events say whether the round took the compacted or the full-width
+        pass.  ``rows``: the ELL rows each round's pass gathered, where the
+        engine counts them, out of ``n_pad``."""
         self._frontier = np.asarray(frontier)
         self._compact_cap = cap
+        self._rows = None if rows is None else np.asarray(rows)
+        self._n_pad = -1 if n_pad is None else int(n_pad)
 
     def finish(self, result, spec, engine_key: str,
                n_vertices: int) -> RunTrace:
         conf = np.asarray(result.conflicts_per_round).reshape(-1)
         rounds = []
         for i, c in enumerate(conf.tolist()):
-            fr_sz = -1
+            fr_sz = rows = -1
             compacted = None
             if self._frontier is not None and i < len(self._frontier):
                 fr_sz = int(self._frontier[i])
                 if self._compact_cap is not None:
                     compacted = fr_sz <= self._compact_cap
+                if self._rows is not None:
+                    rows = int(self._rows[i])
             rounds.append(RoundEvent(round=i, conflicts=int(c),
-                                     frontier=fr_sz, compacted=compacted))
+                                     frontier=fr_sz, compacted=compacted,
+                                     rows=rows))
         return RunTrace(
             spec_key=spec.spec_key(), engine=engine_key,
             n_vertices=int(n_vertices), n_rounds=int(result.n_rounds),
@@ -181,7 +195,7 @@ class RunTracer:
             total_conflicts=int(result.total_conflicts),
             n_colors=int(result.n_colors),
             truncated=bool(result.trace_truncated),
-            wall_s=time.perf_counter() - self._t0)
+            wall_s=time.perf_counter() - self._t0, n_pad=self._n_pad)
 
 
 class TraceCollector:

@@ -3,6 +3,10 @@ claimed RSOC-vs-CAT behaviour (fewer gather passes, same color quality).
 Includes property tests over random graphs — via hypothesis when it is
 installed, via seeded numpy sampling otherwise (the container has no
 network; hard-requiring hypothesis made the whole module uncollectable)."""
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -117,6 +121,155 @@ def test_distance2_coloring():
     # G^2 is denser; needs at least as many colors as G
     res1 = api.color(g, algorithm="rsoc", seed=0)
     assert res.n_colors >= res1.n_colors
+
+
+# --------------------------------------------------------------------------
+# compacted repair passes: bit-identical to the full-width pass
+# --------------------------------------------------------------------------
+
+def _full_width_repair(ctx, ell, osrc, odst, pri, colors, U, max_rounds,
+                       ovf0=False):
+    """The rsoc repair loop with every round a full-width
+    ``_chunked_pass(detect=True)``: the reference the compacted rounds must
+    match bit for bit."""
+    def cond(s):
+        return (s[-2] > 0) & (s[-4] < max_rounds)
+
+    def body(s):
+        colors, U, trace, r, tot, last, ovf = s
+        force = U & (colors < 0)
+        colors2, recolored, n_def, ovf2 = col._chunked_pass(
+            ctx, ell, osrc, odst, pri, colors, U, force, detect=True)
+        trace = trace.at[jnp.minimum(r, col.MAX_ROUNDS_TRACE - 1)].set(n_def)
+        return (colors2, recolored, trace, r + 1, tot + n_def,
+                n_def + force.sum(dtype=jnp.int32), ovf | ovf2)
+
+    s = (colors, U, jnp.zeros((col.MAX_ROUNDS_TRACE,), jnp.int32),
+         jnp.int32(0), jnp.int32(0), jnp.int32(1), jnp.bool_(ovf0))
+    colors, U, trace, r, tot, _, ovf = jax.lax.while_loop(cond, body, s)
+    return colors, r, trace, tot, ovf
+
+
+@functools.partial(jax.jit, static_argnames=("ctx", "max_rounds"))
+def _full_width_rsoc_loop(ell, osrc, odst, pri, ctx, max_rounds):
+    n, n_pad = ctx.n, ctx.n_pad
+    valid = jnp.arange(n_pad) < n
+    colors1, U, _, ovf0 = col._chunked_pass(
+        ctx, ell, osrc, odst, pri, jnp.full((n_pad,), -1, jnp.int32),
+        jnp.zeros((n_pad,), bool), valid, detect=False)
+    out = _full_width_repair(ctx, ell, osrc, odst, pri, colors1, U,
+                             max_rounds, ovf0)
+    return (out[0][:n],) + out[1:]
+
+
+@functools.partial(jax.jit, static_argnames=("ctx", "max_rounds"))
+def _full_width_repair_loop(ell, osrc, odst, pri, colors, U, ctx, max_rounds):
+    return _full_width_repair(ctx, ell, osrc, odst, pri, colors, U,
+                              max_rounds)
+
+
+def _hub_graph():
+    """A hub of degree 40 on a path: past ``ell_cap`` 8, its row spills
+    into the COO overflow."""
+    edges = [(0, v) for v in range(1, 41)] + [(v, v + 1) for v in range(1, 40)]
+    return from_edges(41, np.asarray(edges))
+
+
+def _wide_graph():
+    """1024 vertices on two rings and a hub of degree 600: ELL width 512
+    (blocks of 64 rows) and 88 overflow entries."""
+    v = np.arange(1, 1024)
+    edges = np.concatenate([
+        np.stack([np.zeros(600, np.int64), np.arange(1, 601)], axis=1),
+        np.stack([v, v % 1023 + 1], axis=1),
+        np.stack([v, (v + 6) % 1023 + 1], axis=1)])
+    return from_edges(1024, edges)
+
+
+REPAIR_GRAPHS = {"mesh": (gen.mesh2d(16, 16), 512), "hub": (_hub_graph(), 8),
+                 "wide": (_wide_graph(), 512)}
+REPAIR_CASES = (
+    [pytest.param(g, impl, k, entry, None, id=f"{g}-{impl}-{k}-{entry}")
+     for g in ("mesh", "hub") for impl in ("bitset", "dense")
+     for k in (1, 4, 16) for entry in ("scratch", "seeded")]
+    + [pytest.param("wide", impl, 4, "blocks", None, id=f"wide-{impl}-blocks")
+       for impl in ("bitset", "dense")]
+    + [pytest.param("mesh", "bitset", 4, "scratch", 2, id="mesh-cap2"),
+       pytest.param("wide", "bitset", 4, "blocks", 2, id="wide-cap2")])
+
+
+def _seed_frontier(prob, n_chunks, entry):
+    """A proper coloring with a frontier planted on it, in relabeled space.
+
+    ``seeded``: a fifth of the vertices, half of them uncolored (forced) and
+    half set to color 0.  ``blocks``: all of chunk 0 (several blocks), none
+    of chunk 1, a partial block in chunk 2, every third row uncolored."""
+    n, n_pad = prob.n, prob.n_pad
+    ctx = col.PassContext.for_problem(prob, n_chunks=n_chunks, C=256)
+    colors = np.full(n_pad, -1, np.int32)
+    colors[:n] = np.asarray(col._rsoc_loop(prob.ell, prob.ovf_src,
+                                           prob.ovf_dst, prob.pri, ctx,
+                                           1000)[0])
+    rng = np.random.default_rng(7)
+    U = np.zeros(n_pad, bool)
+    if entry == "seeded":
+        rows = rng.choice(n, size=max(2, n // 5), replace=False)
+        colors[rows[::2]] = -1
+        colors[rows[1::2]] = 0
+    else:
+        cs = n_pad // n_chunks
+        B = col._repair_block(prob.ell.shape[1], cs)
+        rows = np.concatenate([np.arange(cs),
+                               2 * cs + rng.choice(cs, size=B + 5,
+                                                   replace=False)])
+        assert cs >= 3 * B, (cs, B)           # chunk 0 takes several blocks
+        colors[rows[::3]] = -1
+        colors[rows[1::3]] = 0
+    U[rows] = True
+    assert U.sum() <= col._compact_cap(n_pad)  # round 1 is compacted
+    return jnp.asarray(colors), jnp.asarray(U)
+
+
+@pytest.mark.parametrize("gname,impl,n_chunks,entry,C", REPAIR_CASES)
+def test_compacted_repair_matches_full_width(gname, impl, n_chunks, entry, C):
+    """Every output of the rsoc loops, whose repair rounds with a frontier
+    of at most half the rows gather only the frontier's rows, equals that
+    of the same loops with every round full width: colors, rounds, the
+    conflict trace, total defects, the overflow flag, and through
+    ``_run_with_retry`` the final cap and its doublings.  Covers the COO
+    overflow, both forbidden-set impls, 1/4/16 chunks, uncolored seeds,
+    a chunk with several blocks beside one with none, and a cap that
+    overflows and doubles."""
+    g, ell_cap = REPAIR_GRAPHS[gname]
+    prob = col.prepare(g, seed=3, n_chunks=n_chunks, ell_cap=ell_cap, C=C)
+    assert (prob.ovf_src.shape[0] > 0) == (gname != "mesh")
+    if entry == "scratch":
+        loops = (col._rsoc_loop, _full_width_rsoc_loop)
+        args = ()
+    else:
+        loops = (col._rsoc_repair_loop, _full_width_repair_loop)
+        args = _seed_frontier(prob, n_chunks, entry)
+
+    def runner(loop):
+        def run(C_):
+            ctx = col.PassContext.for_problem(prob, n_chunks=n_chunks, C=C_,
+                                              forbidden_impl=impl)
+            return loop(prob.ell, prob.ovf_src, prob.ovf_dst, prob.pri,
+                        *args, ctx, 1000)
+        return run
+
+    first = [runner(loop)(prob.C) for loop in loops]
+    got, want = [col._run_with_retry(runner(loop), prob.C) for loop in loops]
+    assert got[1:] == want[1:]                    # final C, retries
+    if C is not None:
+        assert bool(first[0][-1]) and got[2] >= 1   # the cap overflowed
+    for out, ref in ((first[0], first[1]), (got[0], want[0])):
+        assert len(out) == len(ref) == 5
+        for a, b in zip(out, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if entry == "scratch":
+        assert col.is_proper(g, col._unpermute(np.asarray(got[0][0]),
+                                               prob.perm, prob.n))
 
 
 # --------------------------------------------------------------------------

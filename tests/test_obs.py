@@ -15,6 +15,7 @@ Covers the acceptance criteria of the obs PR:
     invalidation on mutation, queries never observing a half-applied
     batch) are asserted through the new memo counters.
 """
+import dataclasses
 import json
 import re
 import warnings
@@ -201,7 +202,10 @@ def _loop_hlo(g, ell_cap):
 @pytest.mark.parametrize("case", ["mesh", "hub"])
 def test_solve_scopes_name_the_untraced_loop(case):
     """The untraced ``_rsoc_loop`` carries the solve's named scopes in its
-    ``op_name`` metadata, ``overflow`` only where hubs pass ``ell_cap``."""
+    ``op_name`` metadata, ``overflow`` only where hubs pass ``ell_cap``.
+    The compacted repair pass's block loop (the only loop three deep under
+    ``repair``) files its gathers under ``gather``, its snapshot reads
+    under ``overflow`` and its pack, mex and write-back under ``mex``."""
     g, cap = (MESH, 512) if case == "mesh" else (_hub_graph(), 8)
     prob, hlo = _loop_hlo(g, cap)
     assert (prob.ovf_src.shape[0] > 0) == (case == "hub")
@@ -211,6 +215,79 @@ def test_solve_scopes_name_the_untraced_loop(case):
     assert want <= scoped, scoped
     assert ("overflow" in scoped) == (case == "hub")
     assert set(col.SOLVE_SCOPES) == want | {"overflow"}
+
+    def in_blocks(path):
+        parts = path.split("/")
+        return "repair" in parts and parts[parts.index("repair"):].count(
+            "while") == 3
+
+    ops = re.findall(r"= \S+ (gather|scatter)\(.*?op_name=\"([^\"]*)\"", hlo)
+    block_ops = [(op, path.split("/")) for op, path in ops if in_blocks(path)]
+    assert {op for op, _ in block_ops} == {"gather", "scatter"}
+    for op, parts in block_ops:
+        assert ({"gather", "overflow"} if op == "gather" else {"mex"}) \
+            & set(parts), (op, parts)
+    block_scopes = {p for n in names if in_blocks(n)
+                    for p in n.split("/")[:-1]} & set(col.SOLVE_SCOPES)
+    assert block_scopes == {"gather", "mex", "repair"} | (
+        {"overflow"} if case == "hub" else set())
+
+
+def _repair_rows_share():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "metrics",
+        "repair_rows_share.py")
+    spec = importlib.util.spec_from_file_location("repair_rows_share", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_round_rows_count_the_compacted_repair(monkeypatch):
+    """A traced rsoc call records the ELL rows each repair pass gathered:
+    ``n_pad`` in round 1, whose frontier is every vertex, and fewer in the
+    rounds after it, which gather whole blocks of the frontier's rows; the
+    bench's ``repair_rows_share`` reads their share of the trace's
+    ``n_pad``, and reads None for a program whose trace carries no such
+    count."""
+    import types
+    _no_env_trace(monkeypatch)
+    g = gen.mesh2d(32, 32)
+    res = api.color(g, algorithm="rsoc", seed=3, trace=True)
+    prob = col.prepare(g, 3, 16)
+    rounds = res.trace.rounds
+    assert len(rounds) == res.n_rounds >= 2
+    assert res.trace.n_pad == prob.n_pad
+    assert rounds[0].rows == prob.n_pad and rounds[0].compacted is False
+    B = col._repair_block(prob.ell.shape[1], prob.n_pad // 16)
+    for ev in rounds[1:]:
+        assert ev.compacted is True
+        assert ev.frontier <= ev.rows < prob.n_pad and ev.rows % B == 0
+    reader = _repair_rows_share()
+    run = types.SimpleNamespace(n=g.n_vertices,
+                                samples={"run_traces": [res.trace]})
+    share = 100 * sum(e.rows for e in rounds) / (len(rounds) * prob.n_pad)
+    assert reader.read(run) == pytest.approx(share)
+    assert 100 / len(rounds) < share < 100
+
+    @dataclasses.dataclass(frozen=True)
+    class OldRoundEvent:              # a RoundEvent without ``rows``
+        round: int
+        conflicts: int
+        frontier: int = -1
+        compacted: object = None
+
+    old = dataclasses.replace(res.trace, rounds=tuple(
+        OldRoundEvent(e.round, e.conflicts, e.frontier) for e in rounds))
+    assert reader.read(types.SimpleNamespace(
+        n=g.n_vertices, samples={"run_traces": [old]})) is None
+    no_pad = dataclasses.replace(res.trace, n_pad=-1)
+    assert reader.read(types.SimpleNamespace(
+        n=g.n_vertices, samples={"run_traces": [no_pad]})) is None
+    assert reader.read(types.SimpleNamespace(
+        n=g.n_vertices, samples={"run_traces": []})) is None
 
 
 # --------------------------------------------------------------------------
