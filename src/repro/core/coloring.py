@@ -204,26 +204,76 @@ def _pick_C(g: CSRGraph, C: Optional[int]) -> int:
     return int(max(32, -(-c // 32) * 32))
 
 
+def _relabel_rows(g: CSRGraph, perm: np.ndarray,
+                  n_pad: int) -> Optional[np.ndarray]:
+    """The relabeled, row-sorted ELL of ``g`` built straight from its rows,
+    for a graph whose every row fits (``W = max_degree``), or None where a
+    row holds a self-loop or a repeated neighbor.
+
+    ``perm`` is a bijection, so relabeled row ``perm[u]`` is row ``u`` with
+    each id mapped through ``perm`` and sorted: exactly the row that
+    ``from_edges(n, perm[to_edge_list(g)], symmetrize=False)`` builds when
+    there is nothing to drop or merge.  Costs O(n·W) with no global sort;
+    the None cases keep that path, whose self-loop removal and dedup stay
+    as they are."""
+    n = g.n_vertices
+    W = max(1, g.max_degree)
+    deg = g.degrees
+    perm32 = perm.astype(np.int32)
+    # row u of g, ids mapped through perm, in CSR order; a self-loop u-u
+    # reads perm[u] in row u
+    mapped = np.full((n, W), FILL, np.int32)
+    mapped[np.arange(W) < deg[:, None]] = perm32[g.indices]
+    if (mapped == perm32[:, None]).any():
+        return None
+    inv = np.empty(n, np.int64)
+    inv[perm] = np.arange(n)
+    ell = np.empty((n_pad, W), np.int32)
+    ell[n:] = FILL
+    # inv is in range; "clip" lets take write into out without a buffer
+    np.take(mapped, inv, axis=0, out=ell[:n], mode="clip")
+    ell[:n].view(np.uint32).sort(axis=1)         # FILL sorts last
+    # a sorted row's only equal neighbors are its FILL pairs, unless it
+    # repeats an id
+    if (np.count_nonzero(ell[:n, 1:] == ell[:n, :-1])
+            != np.maximum(W - 1 - deg, 0).sum()):
+        return None
+    return ell
+
+
 def prepare(g: CSRGraph, seed: int = 0, n_chunks: int = 16,
             ell_cap: int = 512, C: Optional[int] = None,
             relabel: bool = True) -> ColoringProblem:
     """Relabel, lay out and upload ``g``: three traced phases
     (``prepare.relabel|layout|upload``) nested in the caller's ``prepare``.
-    Under a tracer the upload blocks on the copies, so its time is theirs."""
+    Under a tracer the upload blocks on the copies, so its time is theirs.
+
+    Relabel builds the relabeled, row-sorted ELL straight from ``g``'s rows
+    where every row fits it and no row holds a self-loop or a repeated
+    neighbor (``_relabel_rows``); otherwise it rebuilds a relabeled CSR
+    through ``from_edges`` and layout splits it into ELL and COO overflow.
+    Both give the same problem bit for bit; the phase's ``path`` meta says
+    which ran (``rows`` or ``edges``)."""
     n = g.n_vertices
+    n_pad = -(-max(n, n_chunks) // n_chunks) * n_chunks
     rng = np.random.default_rng(seed)
-    with obs.phase("prepare.relabel"):
+    ell = None
+    with obs.phase("prepare.relabel") as meta:
         perm = (rng.permutation(n).astype(np.int64) if relabel
                 else np.arange(n))
         if relabel:
-            edges = perm[to_edge_list(g).astype(np.int64)]
-            g = from_edges(n, edges, symmetrize=False)
+            if g.max_degree <= ell_cap:
+                ell = _relabel_rows(g, perm, n_pad)
+            meta["path"] = "edges" if ell is None else "rows"
+            if ell is None:
+                edges = perm[to_edge_list(g).astype(np.int64)]
+                g = from_edges(n, edges, symmetrize=False)
     with obs.phase("prepare.layout"):
-        n_pad = -(-max(n, n_chunks) // n_chunks) * n_chunks
         W = max(1, min(g.max_degree, ell_cap))
         deg = g.degrees
         if g.max_degree <= ell_cap:
-            ell = to_ell(g, max_degree=W, pad_vertices_to=n_pad)
+            if ell is None:
+                ell = to_ell(g, max_degree=W, pad_vertices_to=n_pad)
             osrc = np.zeros((0,), np.int32)
             odst = np.zeros((0,), np.int32)
         else:

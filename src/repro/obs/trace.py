@@ -146,12 +146,16 @@ class RunTracer:
         The event is recorded even when the body raises.  A phase opened
         inside another (``prepare.relabel`` inside ``prepare``) is an event
         of its own, under its own name, so a total by name
-        (``RunTrace.phase_wall_s``) never counts a nested phase twice."""
+        (``RunTrace.phase_wall_s``) never counts a nested phase twice.
+
+        The scope yields the event's ``meta`` dict, so the body may add
+        what it learns on the way (``prepare.relabel`` records its
+        ``path``)."""
         from repro.obs.export import annotate
         t0 = time.perf_counter()
         try:
             with annotate(f"repro.{name}"):
-                yield
+                yield meta
         finally:
             self._phases.append(PhaseEvent(name=name,
                                            wall_s=time.perf_counter() - t0,
@@ -220,9 +224,11 @@ def current_tracer() -> Optional[RunTracer]:
 
 def phase(name: str, **meta):
     """``current_tracer().phase(...)`` or a no-op scope — the one-line way
-    for an engine to mark a phase without checking for a tracer first."""
+    for an engine to mark a phase without checking for a tracer first.
+    Either way the scope yields a meta dict the body may add to."""
     t = current_tracer()
-    return t.phase(name, **meta) if t is not None else contextlib.nullcontext()
+    return (t.phase(name, **meta) if t is not None
+            else contextlib.nullcontext(meta))
 
 
 def active_collector() -> Optional[TraceCollector]:

@@ -16,11 +16,12 @@ try:
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro import api
+from repro import api, obs
 from repro.core import coloring as col
 from repro.core.distance2 import color_distance_d, is_distance_d_proper
 from repro.graphs import generators as gen
-from repro.graphs.csr import CSRGraph, from_edges, power_graph
+from repro.graphs.csr import (CSRGraph, from_edges, power_graph, to_edge_list,
+                              to_ell)
 
 
 GRAPHS = {
@@ -270,6 +271,115 @@ def test_compacted_repair_matches_full_width(gname, impl, n_chunks, entry, C):
     if entry == "scratch":
         assert col.is_proper(g, col._unpermute(np.asarray(got[0][0]),
                                                prob.perm, prob.n))
+
+
+# --------------------------------------------------------------------------
+# prepare: relabel straight into the ELL, bit-identical to the edge path
+# --------------------------------------------------------------------------
+
+def _prepare_via_edges(g, seed, n_chunks, ell_cap, relabel):
+    """``col.prepare`` as it was before relabel built the ELL from rows:
+    relabel through an edge list and ``from_edges``, then the ELL and COO
+    overflow split of the relabeled CSR."""
+    n = g.n_vertices
+    rng = np.random.default_rng(seed)
+    perm = rng.permutation(n).astype(np.int64) if relabel else np.arange(n)
+    if relabel:
+        g = from_edges(n, perm[to_edge_list(g).astype(np.int64)],
+                       symmetrize=False)
+    n_pad = -(-max(n, n_chunks) // n_chunks) * n_chunks
+    W = max(1, min(g.max_degree, ell_cap))
+    osrc = odst = np.zeros((0,), np.int32)
+    if g.max_degree <= ell_cap:
+        ell = to_ell(g, max_degree=W, pad_vertices_to=n_pad)
+    else:
+        deg = g.degrees
+        ell = np.full((n_pad, W), -1, np.int32)
+        row = np.repeat(np.arange(n), deg)
+        slot = np.arange(g.n_edges) - np.repeat(g.indptr[:-1], deg)
+        in_ell = slot < W
+        ell[row[in_ell], slot[in_ell]] = g.indices[in_ell]
+        osrc = row[~in_ell].astype(np.int32)
+        odst = g.indices[~in_ell].astype(np.int32)
+    pri = np.full(n_pad, -1, np.int32)
+    pri[:n] = rng.permutation(n).astype(np.int32)
+    return dict(ell=ell, ovf_src=osrc, ovf_dst=odst, pri=pri, perm=perm,
+                n=n, n_pad=n_pad, C=col._pick_C(g, None))
+
+
+def _edit_rows(g, edit):
+    """``g`` with each row replaced by ``edit(u, row)``: a CSR that
+    ``from_edges`` would never build."""
+    rows = [edit(u, g.neighbors(u).tolist()) for u in range(g.n_vertices)]
+    indptr = np.zeros(g.n_vertices + 1, np.int64)
+    np.cumsum([len(r) for r in rows], out=indptr[1:])
+    return CSRGraph(indptr=indptr,
+                    indices=np.asarray(sum(rows, []), np.int32),
+                    n_vertices=g.n_vertices)
+
+
+def _isolated_graph():
+    """A 3-D mesh with every fifth id an isolated vertex."""
+    m = gen.mesh3d(5, 5, 5)
+    ids = np.arange(m.n_vertices) + np.arange(m.n_vertices) // 4
+    n = int(ids[-1]) + 3
+    return from_edges(n, ids[to_edge_list(m)], symmetrize=False)
+
+
+_MESH = gen.mesh3d(6, 6, 6)
+_TOP = int(np.argmax(_MESH.degrees))
+PREPARE_GRAPHS = {
+    "rmat_er": gen.rmat_er(10),
+    "mesh3d": _MESH,
+    "isolated": _isolated_graph(),
+    "odd_n": gen.erdos_renyi(1001, 6.0),
+    # the widest row repeats a neighbor, so the dedup narrows the ELL
+    "dup": _edit_rows(_MESH, lambda u, r: r + r[:1] if u == _TOP else r),
+    "self_loop": _edit_rows(_MESH, lambda u, r: sorted(r + [u])
+                            if u % 7 == 0 else r),
+    # rows in descending order: sorted within each row, nothing dropped
+    "unsorted": _edit_rows(_MESH, lambda u, r: r[::-1]),
+    "hub": _hub_graph(),
+}
+PREPARE_CASES = (
+    [pytest.param(name, seed, 16, 512, True, "rows",
+                  id=f"{name}-seed{seed}")
+     for name in ("rmat_er", "mesh3d", "isolated", "odd_n", "unsorted")
+     for seed in (0, 1, 5)]
+    + [pytest.param("odd_n", 2, 12, 512, True, "rows", id="odd_n-12chunks")]
+    + [pytest.param(name, 3, 16, 512, True, "edges", id=name)
+       for name in ("dup", "self_loop")]
+    + [pytest.param("hub", 3, 16, 8, True, "edges", id="hub-over-cap"),
+       pytest.param("rmat_er", 4, 16, 512, False, None, id="no-relabel")])
+
+
+@pytest.mark.parametrize("gname,seed,n_chunks,ell_cap,relabel,path",
+                         PREPARE_CASES)
+def test_prepare_matches_edge_path(gname, seed, n_chunks, ell_cap, relabel,
+                                   path):
+    """``prepare`` gives the problem the edge-list relabel gave, field for
+    field, on every path: the row path (isolated vertices, padded rows,
+    unsorted input rows), the fallback to the edge path on a repeated
+    neighbor or a self-loop (which it drops and merges as before), a hub
+    over ``ell_cap`` (COO overflow) and no relabel.  The traced
+    ``prepare.relabel`` phase names the path that ran."""
+    g = PREPARE_GRAPHS[gname]
+    with obs.run_tracer() as tracer:
+        prob = col.prepare(g, seed=seed, n_chunks=n_chunks, ell_cap=ell_cap,
+                           relabel=relabel)
+    want = _prepare_via_edges(g, seed, n_chunks, ell_cap, relabel)
+    for field, ref in want.items():
+        got = getattr(prob, field)
+        if isinstance(ref, np.ndarray):
+            got = np.asarray(got)
+            assert got.dtype == ref.dtype, field
+            np.testing.assert_array_equal(got, ref, err_msg=field)
+        else:
+            assert got == ref, field
+    meta = [p.meta for p in tracer._phases if p.name == "prepare.relabel"]
+    assert meta == [{} if path is None else {"path": path}]
+    if gname == "dup":
+        assert prob.ell.shape[1] == g.max_degree - 1
 
 
 # --------------------------------------------------------------------------
